@@ -366,23 +366,23 @@ def pack_stacked_weights(
 ) -> PackedStackedTensor:
     """RaZeR-quantize a stacked (E, K, N) bank per-entry and bit-pack it.
 
-    Each entry is packed exactly as ``pack_weight`` would pack it in isolation
-    (independent tensor scales), so ``pack_stacked_weights(w)[e]`` round-trips
-    bit-for-bit with ``pack_weight(w[e])``.
+    Each entry is packed by ``pack_weight`` in isolation (independent tensor
+    scales), so ``pack_stacked_weights(w)[e]`` round-trips bit-for-bit with
+    ``pack_weight(w[e])``.  Entries widen to f32 one at a time: the quantizer's
+    temporaries for a whole full-width bank would not fit on one chip.
     """
     if w.ndim != 3:
         raise ValueError("pack_stacked_weights expects a 3-D (E, K, N) bank")
     e, k, n = w.shape
-
-    def one(we):
-        pw = pack_weight(we, sv_magnitudes=sv_magnitudes, block_size=block_size)
-        return pw.codes, pw.scale_meta, pw.tensor_scale
-
-    codes, scale_meta, tensor_scale = jax.vmap(one)(jnp.asarray(w, jnp.float32))
+    packed = [
+        pack_weight(jnp.asarray(w[i], jnp.float32), sv_magnitudes=sv_magnitudes,
+                    block_size=block_size)
+        for i in range(e)
+    ]
     return PackedStackedTensor(
-        codes=codes,
-        scale_meta=scale_meta,
-        tensor_scale=tensor_scale,
+        codes=jnp.stack([pw.codes for pw in packed]),
+        scale_meta=jnp.stack([pw.scale_meta for pw in packed]),
+        tensor_scale=jnp.stack([pw.tensor_scale for pw in packed]),
         sv_magnitudes=tuple(float(m) for m in sv_magnitudes),
         shape=(e, k, n),
     )

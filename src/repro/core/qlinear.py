@@ -200,7 +200,6 @@ def _tp_packed_matmul(x, w, entry):
     tp = ctx.axis_size(axis)
     _, localize = entry.shard_packed_fn(w, axis)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.ops import reduce_scatter_epilogue
@@ -211,9 +210,9 @@ def _tp_packed_matmul(x, w, entry):
         y = entry.matmul_kernel(x_l, localize(w_l, tp))
         return reduce_scatter_epilogue(y, axis)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh, in_specs=(io_spec, specs), out_specs=io_spec,
-        check_rep=False,
+        check_vma=False,
     )(x, w)
 
 

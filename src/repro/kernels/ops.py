@@ -94,11 +94,15 @@ def _largest_divisor(n: int, candidates) -> int:
 
 
 def pick_blocks(m: int, n: int, k: int):
-    """MXU-aligned block shapes that divide the problem (the §4.3 auto-tuner's
-    TPU analogue picks from this lattice; see benchmarks/kernel_bench.py)."""
-    bm = _largest_divisor(m, (256, 128, 64, 32, 16, 8, 4, 2, 1))
-    bn = _largest_divisor(n, (256, 128, 64, 32, 16, 8))
-    bk = _largest_divisor(k, (512, 256, 128, 64, 32, 16))
+    """MXU-aligned block shapes (the §4.3 auto-tuner's TPU analogue picks
+    from this lattice; see benchmarks/kernel_bench.py).
+
+    Mosaic tiles the last two block dims by (8, 128) unless a block spans
+    the whole dim: bm divides M rounded up to 8 (the wrappers pad M), and
+    bn / bk are 128-multiples dividing N / K, else the whole dim."""
+    bm = _largest_divisor(-(-m // 8) * 8, (256, 128, 64, 32, 16, 8))
+    bn = _largest_divisor(n, (256, 128))
+    bk = _largest_divisor(k, (512, 256, 128))
     return bm, bn, bk
 
 

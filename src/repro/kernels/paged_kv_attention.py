@@ -22,13 +22,14 @@ loop of the contiguous kernel.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .razer_kv_attention import _dequant_tile
+from .razer_kv_attention import _dequant_tile, _finish, _scores, merge_even_odd, split_even_odd
 
 __all__ = ["paged_kv_attention_pallas", "paged_kv_attention_verify_pallas"]
 
@@ -44,12 +45,11 @@ def _kernel(pt_ref, cur_len_ref, q_ref, kc_ref, km_ref, vc_ref, vm_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     cur_len = cur_len_ref[pl.program_id(0)]  # per-slot valid length
-    q = q_ref[...].astype(jnp.float32)  # (G, hd)
-    k = _dequant_tile(kc_ref[...], km_ref[...], hd)  # (ps, hd) f32
-    v = _dequant_tile(vc_ref[...], vm_ref[...], hd)
+    k_even, k_odd = _dequant_tile(kc_ref[...], km_ref[...], hd)  # (ps, hd//2) f32
+    v_even, v_odd = _dequant_tile(vc_ref[...], vm_ref[...], hd)
 
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (G, ps)
+    scale = 1.0 / math.sqrt(hd)
+    s = _scores(q_ref, k_even, k_odd) * scale  # (G, ps)
     # mask on LOGICAL positions: page pi holds [pi*ps, (pi+1)*ps)
     pos = pi * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
     s = jnp.where(pos < cur_len, s, -1e30)
@@ -59,12 +59,13 @@ def _kernel(pt_ref, cur_len_ref, q_ref, kc_ref, km_ref, vc_ref, vm_ref, o_ref,
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(p, v, preferred_element_type=jnp.float32)
+    acc_ref[0] = acc_ref[0] * alpha + jnp.dot(p, v_even, preferred_element_type=jnp.float32)
+    acc_ref[1] = acc_ref[1] * alpha + jnp.dot(p, v_odd, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(pi == npages - 1)
     def _done():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        _finish(o_ref, acc_ref, l_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -82,7 +83,7 @@ def paged_kv_attention_pallas(q, k_codes, k_meta, v_codes, v_meta, page_table,
     g = h // kvh
     grid = (b, kvh, npages)
 
-    qg = q.reshape(b, kvh, g, hd)
+    qg = split_even_odd(q.reshape(b, kvh, g, hd)).swapaxes(2, 3)  # (B, KVH, 2, G, hd//2)
     # (P, ps, KVH, x) -> (P, KVH, ps, x): one physical page per grid step is a
     # contiguous (ps, x) block for its kv head
     kc = k_codes.transpose(0, 2, 1, 3)
@@ -97,7 +98,8 @@ def paged_kv_attention_pallas(q, k_codes, k_meta, v_codes, v_meta, page_table,
             num_scalar_prefetch=2,  # page_table, cur_len
             grid=grid,
             in_specs=[
-                pl.BlockSpec((None, None, g, hd), lambda bi, ki, pi, pt, cl: (bi, ki, 0, 0)),
+                pl.BlockSpec((None, None, 2, g, hd // 2),
+                             lambda bi, ki, pi, pt, cl: (bi, ki, 0, 0, 0)),
                 # the gather: logical page pi of sequence bi lives at physical
                 # page pt[bi, pi] -- the index map IS the page-table lookup
                 pl.BlockSpec((None, None, ps, hd // 2),
@@ -109,21 +111,23 @@ def paged_kv_attention_pallas(q, k_codes, k_meta, v_codes, v_meta, page_table,
                 pl.BlockSpec((None, None, ps, hd // 16),
                              lambda bi, ki, pi, pt, cl: (pt[bi, pi], ki, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((None, None, g, hd), lambda bi, ki, pi, pt, cl: (bi, ki, 0, 0)),
+            out_specs=pl.BlockSpec((None, None, 2, g, hd // 2),
+                                   lambda bi, ki, pi, pt, cl: (bi, ki, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((g, 1), jnp.float32),
                 pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, hd), jnp.float32),
+                pltpu.VMEM((2, g, hd // 2), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, 2, g, hd // 2), jnp.float32),
         interpret=interpret,
+        name="razer_paged_attention",
     )(
         jnp.asarray(page_table, jnp.int32),
         jnp.broadcast_to(jnp.asarray(cur_len, jnp.int32).reshape(-1), (b,)),
         qg, kc, km, vc, vm,
     )
-    return out.reshape(b, h, hd)
+    return merge_even_odd(out.swapaxes(2, 3)).reshape(b, h, hd)
 
 
 def _verify_kernel(pt_ref, cur_len_ref, q_ref, kc_ref, km_ref, vc_ref, vm_ref,
@@ -141,12 +145,11 @@ def _verify_kernel(pt_ref, cur_len_ref, q_ref, kc_ref, km_ref, vc_ref, vm_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     cur_len = cur_len_ref[pl.program_id(0)]
-    q = q_ref[...].reshape(t * g, hd).astype(jnp.float32)  # (T*G, hd)
-    k = _dequant_tile(kc_ref[...], km_ref[...], hd)  # (ps, hd) f32
-    v = _dequant_tile(vc_ref[...], vm_ref[...], hd)
+    k_even, k_odd = _dequant_tile(kc_ref[...], km_ref[...], hd)  # (ps, hd//2) f32
+    v_even, v_odd = _dequant_tile(vc_ref[...], vm_ref[...], hd)
 
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (T*G, ps)
+    scale = 1.0 / math.sqrt(hd)
+    s = _scores(q_ref, k_even, k_odd) * scale  # (T*G, ps)
     pos = pi * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
     # row r of the flattened (T*G) query block belongs to query index r // g
     qt = jax.lax.broadcasted_iota(jnp.int32, (t * g, 1), 0) // g
@@ -157,13 +160,13 @@ def _verify_kernel(pt_ref, cur_len_ref, q_ref, kc_ref, km_ref, vc_ref, vm_ref,
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(p, v, preferred_element_type=jnp.float32)
+    acc_ref[0] = acc_ref[0] * alpha + jnp.dot(p, v_even, preferred_element_type=jnp.float32)
+    acc_ref[1] = acc_ref[1] * alpha + jnp.dot(p, v_odd, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(pi == npages - 1)
     def _done():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).reshape(
-            t, g, hd).astype(o_ref.dtype)
+        _finish(o_ref, acc_ref, l_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -182,9 +185,11 @@ def paged_kv_attention_verify_pallas(q, k_codes, k_meta, v_codes, v_meta,
     g = h // kvh
     grid = (b, kvh, npages)
 
-    # (B, T, H, hd) -> (B, KVH, T, G, hd): one (T, G, hd) query block per
-    # (slot, kv head) grid step, flattened to (T*G, hd) rows in the kernel
-    qg = q.reshape(b, t, kvh, g, hd).transpose(0, 2, 1, 3, 4)
+    # (B, T, H, hd) -> (B, KVH, 2, T*G, hd//2): one block of T*G query rows
+    # (row r is query r // g) per (slot, kv head) grid step, head dims split
+    # even/odd like the dequantized K/V tiles
+    qg = q.reshape(b, t, kvh, g, hd).transpose(0, 2, 1, 3, 4).reshape(b, kvh, t * g, hd)
+    qg = split_even_odd(qg).swapaxes(2, 3)
     kc = k_codes.transpose(0, 2, 1, 3)
     km = k_meta.transpose(0, 2, 1, 3)
     vc = v_codes.transpose(0, 2, 1, 3)
@@ -197,7 +202,7 @@ def paged_kv_attention_verify_pallas(q, k_codes, k_meta, v_codes, v_meta,
             num_scalar_prefetch=2,  # page_table, cur_len
             grid=grid,
             in_specs=[
-                pl.BlockSpec((None, None, t, g, hd),
+                pl.BlockSpec((None, None, 2, t * g, hd // 2),
                              lambda bi, ki, pi, pt, cl: (bi, ki, 0, 0, 0)),
                 pl.BlockSpec((None, None, ps, hd // 2),
                              lambda bi, ki, pi, pt, cl: (pt[bi, pi], ki, 0, 0)),
@@ -208,20 +213,22 @@ def paged_kv_attention_verify_pallas(q, k_codes, k_meta, v_codes, v_meta,
                 pl.BlockSpec((None, None, ps, hd // 16),
                              lambda bi, ki, pi, pt, cl: (pt[bi, pi], ki, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((None, None, t, g, hd),
+            out_specs=pl.BlockSpec((None, None, 2, t * g, hd // 2),
                                    lambda bi, ki, pi, pt, cl: (bi, ki, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((t * g, 1), jnp.float32),
                 pltpu.VMEM((t * g, 1), jnp.float32),
-                pltpu.VMEM((t * g, hd), jnp.float32),
+                pltpu.VMEM((2, t * g, hd // 2), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, t, g, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, 2, t * g, hd // 2), jnp.float32),
         interpret=interpret,
+        name="razer_paged_attention_verify",
     )(
         jnp.asarray(page_table, jnp.int32),
         jnp.broadcast_to(jnp.asarray(cur_len, jnp.int32).reshape(-1), (b,)),
         qg, kc, km, vc, vm,
     )
-    # (B, KVH, T, G, hd) -> (B, T, H, hd)
+    # (B, KVH, 2, T*G, hd//2) -> (B, T, H, hd)
+    out = merge_even_odd(out.swapaxes(2, 3)).reshape(b, kvh, t, g, hd)
     return out.transpose(0, 2, 1, 3, 4).reshape(b, t, h, hd)

@@ -103,6 +103,7 @@ def razer_grouped_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((e, m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name="razer_grouped_matmul",
     )(x, codes, scale_meta)
 
 

@@ -34,25 +34,23 @@ __all__ = ["razer_matmul_pallas", "razer_matmul_kshard_pallas"]
 
 
 def _decode_e3m3_scale(code):
-    """6-bit E3M3 scale code -> f32 value, pure arithmetic (no table gather).
+    """6-bit E3M3 scale code (int32) -> f32 value, pure arithmetic (no table gather).
 
     value = 2^(1-bias) * (m/8)        if e == 0   (bias = 3)
           = 2^(e-bias) * (1 + m/8)    otherwise
     """
-    code = code.astype(jnp.int32)
     e = code >> 3
     m = (code & 7).astype(jnp.float32)
-    sub = jnp.exp2(jnp.float32(1 - 3)) * (m / 8.0)
+    sub = 2.0 ** (1 - 3) * (m / 8.0)
     nrm = jnp.exp2((e - 3).astype(jnp.float32)) * (1.0 + m / 8.0)
     return jnp.where(e == 0, sub, nrm)
 
 
-def _decode_fp4_tile(codes, sv):
-    """FP4 codes (bk, bn) + per-element special value -> f32 values.
+def _decode_fp4_tile(c, sv):
+    """FP4 codes (int32) + per-element special value -> f32 values.
 
     Implements Eq. 5 plus the RaZeR remap: code 8 (-0) decodes to ``sv``.
     """
-    c = codes.astype(jnp.int32)
     s = c >> 3
     e = (c >> 1) & 0b11
     m = (c & 1).astype(jnp.float32)
@@ -65,19 +63,18 @@ def _decode_weight_tile(packed, sm, *, block_k, m0, m1, compute_dtype):
     """One wire-format weight tile -> dense (bk, bn) values in compute_dtype.
 
     packed: (bk//2, bn) uint8 code bytes; sm: (bk//16, bn) uint8 scale/meta
-    bytes.  Shared by the 2-D and the grouped kernels -- the wire format has
+    bytes.  The bytes widen to int32 first: Mosaic lowers no 8-bit shifts.
+    Shared by the 2-D and the grouped kernels -- the wire format has
     exactly one decoder."""
-    lo = (packed & 0xF).astype(jnp.uint8)
-    hi = (packed >> 4).astype(jnp.uint8)
+    packed = packed.astype(jnp.int32)
+    sm = sm.astype(jnp.int32)
     bk2, bn = packed.shape
-    codes = jnp.stack([lo, hi], axis=1).reshape(bk2 * 2, bn)  # interleave along K
+    codes = jnp.stack([packed & 0xF, packed >> 4], axis=1).reshape(bk2 * 2, bn)  # interleave along K
 
     scale = _decode_e3m3_scale(sm & 0x3F)
-    meta = (sm >> 6).astype(jnp.int32)
-    select = (meta >> 1) & 1
-    sign = meta & 1
-    sv_mag = jnp.where(select == 1, jnp.float32(m1), jnp.float32(m0))
-    sv = sv_mag * jnp.where(sign == 1, -1.0, 1.0)
+    meta = sm >> 6
+    sv_mag = jnp.where(((meta >> 1) & 1) == 1, jnp.float32(m1), jnp.float32(m0))
+    sv = jnp.where((meta & 1) == 1, -sv_mag, sv_mag)
 
     # broadcast per-block (bk//16, bn) -> per-element (bk, bn)
     nblk = block_k // 16
@@ -152,6 +149,7 @@ def razer_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name="razer_matmul",
     )(x, codes, scale_meta)
 
 

@@ -25,15 +25,6 @@ HBM_BW = 819e9  # bytes/s
 ICI_BW = 50e9  # bytes/s per link
 
 
-def xla_cost_analysis(compiled) -> Dict:
-    """compiled.cost_analysis() normalized across jax versions (newer jax
-    returns a flat dict, older returns a one-dict-per-device list)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def param_counts(cfg: ArchConfig) -> Dict[str, float]:
     """(total, expert, non_expert, active) parameter counts from the tree."""
     shapes = jax.eval_shape(lambda: tf.init_params(jax.random.PRNGKey(0), cfg))

@@ -37,7 +37,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, SHAPES, cells, get_config
 from repro.launch import costmodel
-from repro.launch.costmodel import xla_cost_analysis
 from repro.models import transformer as tf
 from repro.models.config import ArchConfig
 from repro.models.inputs import input_specs
@@ -239,7 +238,7 @@ def _cost_of(cfg, shape, mesh, build_fn=None) -> Dict[str, float]:
     try:
         lowered = build_fn(cfg, shape, mesh)
         compiled = lowered.compile()
-        ca = xla_cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text())
         return {
             "flops": float(ca.get("flops", 0.0)),
@@ -297,7 +296,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool, *, with_cost: bool 
         "output_bytes": int(ma.output_size_in_bytes),
         "temp_bytes": int(ma.temp_size_in_bytes),
     }
-    ca = xla_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     rec["cost_raw"] = {
         "flops": float(ca.get("flops", 0.0)),
         "bytes": float(ca.get("bytes accessed", 0.0)),

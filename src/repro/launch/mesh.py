@@ -7,15 +7,25 @@ explicitly builds a mesh (the dry-run sets XLA_FLAGS first).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_serving_mesh", "make_test_mesh"]
+__all__ = ["auto_mesh", "make_production_mesh", "make_serving_mesh"]
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto.
+
+    ``jax.make_mesh`` now defaults to Explicit axes, and
+    ``jax.lax.with_sharding_constraint`` -- which the activation rules in
+    ``parallel.sharding`` apply -- accepts only Auto axes."""
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_serving_mesh(*, ep: int | None = None, tp: int = 1):
@@ -41,12 +51,4 @@ def make_serving_mesh(*, ep: int | None = None, tp: int = 1):
             f"are visible (set XLA_FLAGS=--xla_force_host_platform_device_count"
             f"=N for host-CPU testing)"
         )
-    return jax.make_mesh((ep, tp), ("data", "model"))
-
-
-def make_test_mesh(shape=(1, 1), axes=("data", "model")):
-    """Tiny mesh over however many local devices exist (CPU tests)."""
-    n = len(jax.devices())
-    if shape == (1, 1) and n > 1:
-        shape = (n, 1)
-    return jax.make_mesh(shape, axes)
+    return auto_mesh((ep, tp), ("data", "model"))

@@ -148,9 +148,7 @@ def measure(cfg, shape, mesh, build_fn) -> Dict:
     ma = compiled.memory_analysis()
     rec["temp_gb"] = round(ma.temp_size_in_bytes / 1e9, 2)
     rec["args_gb"] = round(ma.argument_size_in_bytes / 1e9, 3)
-    from repro.launch.costmodel import xla_cost_analysis
-
-    ca = xla_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     rec["flops_raw"] = float(ca.get("flops", 0))
     rec["bytes_raw"] = float(ca.get("bytes accessed", 0))
     rec["coll_raw"] = collective_bytes(compiled.as_text()).get("total", 0.0)

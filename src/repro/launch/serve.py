@@ -60,6 +60,7 @@ wire format (0.28x of bf16):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -67,6 +68,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.policy import QuantPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tf
 from repro.serving.engine import Engine, ServeConfig
 
@@ -202,10 +204,16 @@ def main(argv=None):
             ap.error("--kv-audit results land in the quant report's 'kv' "
                      "section; add --quant-report OUT.json")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    params = tf.init_params(jax.random.PRNGKey(0), cfg)
+    # serve from a checkpoint in the compute dtype (published checkpoints are
+    # bf16): an f32 tree of a 3B model alone fills most of a 16 GB chip.
+    # Jitted, so the per-layer inits are written straight into the stacked
+    # leaves instead of being stacked from eager copies
+    cfg = dataclasses.replace(cfg, param_dtype=cfg.compute_dtype)
+    params = jax.jit(tf.init_params, static_argnums=1)(jax.random.PRNGKey(0), cfg)
     if args.ckpt:
         from repro.train.checkpoint import restore_checkpoint
 

@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.policy import QuantPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tf
 from repro.parallel.sharding import param_sharding_tree, sharding_ctx
 from repro.train.checkpoint import CheckpointManager, latest_step, restore_checkpoint
@@ -42,6 +43,7 @@ def main(argv=None):
     ap.add_argument("--qat", action="store_true", help="RaZeR fake-quant QAT forward")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

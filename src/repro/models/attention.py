@@ -273,6 +273,30 @@ def gqa_forward(x, p, cfg: ArchConfig, *, quant: QuantLike = DEFAULT_QUANT,
     return qlinear(out.reshape(b, s, -1), p["wo"], quant)
 
 
+def paged_attention(kernel, q, cache, pages, cur_len):
+    """``kernel(q, cache, pages, cur_len)`` over the paged pool; under an
+    active mesh, inside ``shard_map``: XLA cannot partition a Pallas kernel.
+    Each device takes the kv heads of its model-axis slice (their q heads
+    are contiguous) and the batch rows of its data-axis slice, where those
+    divide; the rest of each operand is replicated."""
+    from repro.parallel.sharding import get_ctx
+
+    ctx = get_ctx()
+    if ctx is None or ctx.mesh is None:
+        return kernel(q, cache, pages, cur_len)
+    from jax.sharding import PartitionSpec as P
+
+    kvh = cache["k_codes"].shape[2]
+    heads = ctx.model_axis if ctx.model_axis and kvh % ctx.axis_size(ctx.model_axis) == 0 else None
+    rows = ctx.data_axis if ctx.data_axis and q.shape[0] % ctx.axis_size(ctx.data_axis) == 0 else None
+    q_spec = P(rows, None, heads, None)
+    pool_spec = {name: P(None, None, heads, None) for name in cache}
+    return jax.shard_map(
+        kernel, mesh=ctx.mesh, in_specs=(q_spec, pool_spec, P(rows, None), P(rows)),
+        out_specs=q_spec, check_vma=False,
+    )(q, cache, pages, cur_len)
+
+
 def gqa_decode(x, p, cfg: ArchConfig, cache, cur_len, *, quant: QuantLike = DEFAULT_QUANT,
                window: int = 0, positions3=None, pages=None):
     """One-token decode. cache = dict(k, v) [bf16], the RaZeR-packed layout
@@ -304,7 +328,7 @@ def gqa_decode(x, p, cfg: ArchConfig, cache, cur_len, *, quant: QuantLike = DEFA
             "v_codes": cache["v_codes"].at[pid, slot].set(vc),
             "v_meta": cache["v_meta"].at[pid, slot].set(vm),
         }
-        out = kops.razer_paged_kv_attention(q, cache, pages, cl + 1)
+        out = paged_attention(kops.razer_paged_kv_attention, q, cache, pages, cl + 1)
         y = qlinear(out.reshape(b, 1, -1), p["wo"], quant)
         return y, cache
     if "k_codes" in cache:
@@ -370,7 +394,7 @@ def gqa_decode_verify(x, p, cfg: ArchConfig, cache, cur_len, *,
         "v_codes": cache["v_codes"].at[pid, slot].set(vc),
         "v_meta": cache["v_meta"].at[pid, slot].set(vm),
     }
-    out = kops.razer_paged_kv_attention_verify(q, cache, pages, cl)
+    out = paged_attention(kops.razer_paged_kv_attention_verify, q, cache, pages, cl)
     y = qlinear(out.reshape(b, t, -1), p["wo"], quant)
     return y, cache
 

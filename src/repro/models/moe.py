@@ -141,8 +141,6 @@ def _expert_parallel_ffn(buf, we, gentry, ctx, ep: int, tp: int = 1):
     Single-device meshes never reach this function -- ``moe_forward`` gates
     on ep > 1 or tp > 1 and otherwise runs the unsharded launch.
     """
-    from jax.experimental.shard_map import shard_map
-
     from repro.kernels.ops import reduce_scatter_epilogue
     from repro.parallel.collectives import (
         combine_from_expert_shards,
@@ -202,12 +200,12 @@ def _expert_parallel_ffn(buf, we, gentry, ctx, ep: int, tp: int = 1):
     else:
         body, g_ax = ffn_replicated_tokens, None
     buf_spec = P(g_ax, None, None, tax)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=ctx.mesh,
         in_specs=(buf_spec, gateup_specs, gateup_specs, down_specs),
         out_specs=buf_spec,
-        check_rep=False,
+        check_vma=False,
     )(buf, we["gate"], we["up"], we["down"])
 
 

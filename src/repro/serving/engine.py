@@ -96,14 +96,14 @@ def pack_model_weights(params, cfg: ArchConfig, quant: Union[QuantPolicy, QuantC
             # or the whole trio dense -- the symmetric condition guarantees
             # all three leaves decide identically (all-or-none per bank).
             bs = spec.effective_block_size
+            # (the bank widens to f32 one expert at a time inside the pack:
+            # a whole f32 bank of a full-width MoE layer alone fills a chip)
             if leaf.ndim == 3 and _packable(spec, leaf, 1) and leaf.shape[2] % bs == 0:
-                return spec.pack_stacked(leaf.astype(jnp.float32))
+                return spec.pack_stacked(leaf)
             if leaf.ndim == 4 and _packable(spec, leaf, 2) and leaf.shape[3] % bs == 0:
                 # scan-stacked (L, E, d_in, d_out): one grouped container per
                 # scan layer, restacked leaf-wise (scan slices them back out)
-                packed = [
-                    spec.pack_stacked(leaf[i].astype(jnp.float32)) for i in range(leaf.shape[0])
-                ]
+                packed = [spec.pack_stacked(leaf[i]) for i in range(leaf.shape[0])]
                 return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *packed)
             return leaf
         if leaf.ndim == 2 and _packable(spec, leaf, 0):
@@ -406,6 +406,16 @@ class Engine:
             jnp.asarray(c, jnp.int32), jnp.asarray(s, jnp.int32),
             page_size=ps)
 
+    def default_pool_cfg(self, max_slots: int):
+        """The pool ``serve`` builds when given none: 16-token pages, room for
+        every slot at ``max_len``."""
+        from repro.serving.pagepool import PagePoolConfig
+
+        ps = 16
+        pages_per_seq = -(-self.scfg.max_len // ps)
+        return PagePoolConfig(num_pages=max_slots * pages_per_seq, page_size=ps,
+                              max_len=self.scfg.max_len)
+
     def _as_requests(self, requests, n_new: int):
         """Normalize a request stream (``scheduler.Request`` or raw token-id
         prompts, freely mixed) into a list of ``Request``.  Raw prompts get
@@ -481,8 +491,7 @@ class Engine:
         throughput / pool / prefix-cache / speculation stats, with exact
         p50/p95/p99 TTFT / latency / per-token-latency properties)."""
         from repro.obs import NULL_TRACER, Clock
-        from repro.serving.pagepool import (KVPagePool, PagePoolConfig,
-                                            install_pool_metrics)
+        from repro.serving.pagepool import KVPagePool, install_pool_metrics
         from repro.serving.prefixcache import PrefixCache, install_cache_metrics
         from repro.serving.scheduler import Request, Scheduler, SchedulerConfig
 
@@ -495,13 +504,7 @@ class Engine:
         spec = self._speculator(draft_policy) if k else None
         n_new = max_new_tokens or self.scfg.max_new_tokens
         reqs = self._as_requests(requests, n_new)
-        if pool_cfg is None:
-            ps = 16
-            pages_per_seq = -(-self.scfg.max_len // ps)
-            pool_cfg = PagePoolConfig(
-                num_pages=sched_cfg.max_slots * pages_per_seq,
-                page_size=ps, max_len=self.scfg.max_len)
-        pool = KVPagePool(self.cfg, pool_cfg)
+        pool = KVPagePool(self.cfg, pool_cfg or self.default_pool_cfg(sched_cfg.max_slots))
         if kv_audit is not None:
             pool.set_kv_audit(kv_audit)
         cache = PrefixCache(pool) if prefix_cache else None

@@ -12,13 +12,15 @@ fewer than 8 devices the expert-parallel tests skip rather than fail.
 import jax
 import pytest
 
+from repro.launch.mesh import auto_mesh
+
 
 @pytest.fixture
 def ep_mesh():
     """An 8-way expert-parallel ("data", "model") = (8, 1) host-CPU mesh."""
     if len(jax.devices()) < 8:
         pytest.skip("needs XLA_FLAGS=--xla_force_host_platform_device_count=8")
-    return jax.make_mesh((8, 1), ("data", "model"))
+    return auto_mesh((8, 1), ("data", "model"))
 
 
 @pytest.fixture
@@ -30,9 +32,9 @@ def tp_mesh():
     exercises the 2-D ep x tp composition."""
     n = len(jax.devices())
     if n >= 4:
-        return jax.make_mesh((2, 2), ("data", "model"))
+        return auto_mesh((2, 2), ("data", "model"))
     if n >= 2:
-        return jax.make_mesh((1, 2), ("data", "model"))
+        return auto_mesh((1, 2), ("data", "model"))
     pytest.skip("needs XLA_FLAGS=--xla_force_host_platform_device_count=2 (or more)")
 
 
@@ -41,4 +43,4 @@ def eptp_mesh():
     """The full 2-D (4, 2) ep x tp host-CPU mesh (8 devices)."""
     if len(jax.devices()) < 8:
         pytest.skip("needs XLA_FLAGS=--xla_force_host_platform_device_count=8")
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return auto_mesh((4, 2), ("data", "model"))

@@ -121,13 +121,11 @@ def test_triangular_halves_flops():
 
     q, k, v = _qkv(1, 128, 2, 2, 16, seed=17)
     f = lambda q, k, v: chunked_attention(q, k, v, causal=True, q_chunk=16, kv_chunk=16)
-    from repro.launch.costmodel import xla_cost_analysis
-
-    dense = xla_cost_analysis(jax.jit(f).lower(q, k, v).compile()).get("flops", 0)
+    dense = jax.jit(f).lower(q, k, v).compile().cost_analysis().get("flops", 0)
     # dense path hides flops in a scan body; unroll comparison via triangular's
     # static form vs the analytic rectangle instead
     tok = ATTN_SCHEDULE.set("triangular")
-    tri = xla_cost_analysis(jax.jit(f).lower(q, k, v).compile()).get("flops", 0)
+    tri = jax.jit(f).lower(q, k, v).compile().cost_analysis().get("flops", 0)
     ATTN_SCHEDULE.reset(tok)
     t = 128 // 16
     rect = 2 * 2 * (128 * 128) * 16 * 2  # qk+pv, h=2, full rectangle

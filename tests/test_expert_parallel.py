@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import registry
 from repro.core.packing import PackedStackedTensor, pack_stacked_weights
 from repro.core.policy import QuantPolicy
+from repro.launch.mesh import auto_mesh
 from repro.models import moe as moe_mod
 from repro.models.config import ArchConfig
 from repro.parallel.sharding import (
@@ -98,7 +99,7 @@ def test_local_shard_rejects_indivisible():
 
 
 def test_stacked_bank_specs_fallbacks():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     pst = pack_stacked_weights(jnp.ones((6, 32, 16)))
     # divisible by ep=1: plan exists (trivially replication-equivalent)
     assert stacked_bank_specs(pst, mesh) is not None
@@ -211,7 +212,7 @@ def test_sharded_decode_shape_keeps_banks_sharded(ep_mesh):
 
 def test_single_device_mesh_reduces_bit_exactly():
     """A (1, 1) mesh must take the existing unsharded launch: bit-exact."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     cfg = _moe_cfg(n_experts=4)
     _, packed = _packed_moe_params(cfg, seed=4)
     # 25 tokens: gcd(25, want) == 1 for every want, so group count matches
@@ -242,8 +243,6 @@ def test_indivisible_e_falls_back_replicated(ep_mesh):
 # collectives: dispatch/combine round-trip + divisibility error
 # ---------------------------------------------------------------------------
 def test_dispatch_combine_roundtrip(ep_mesh):
-    from jax.experimental.shard_map import shard_map
-
     from repro.parallel.collectives import (
         combine_from_expert_shards,
         dispatch_to_expert_shards,
@@ -255,24 +254,22 @@ def test_dispatch_combine_roundtrip(ep_mesh):
     def roundtrip(b):
         return combine_from_expert_shards(dispatch_to_expert_shards(b, "data"), "data")
 
-    out = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(
         roundtrip, mesh=ep_mesh, in_specs=P("data"), out_specs=P("data"),
-        check_rep=False,
+        check_vma=False,
     ))(buf)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(buf))
 
 
 def test_dispatch_rejects_indivisible_e(ep_mesh):
-    from jax.experimental.shard_map import shard_map
-
     from repro.parallel.collectives import dispatch_to_expert_shards
 
     buf = jnp.zeros((8, 6, 4, 8), jnp.float32)  # E=6 over ep=8
     with pytest.raises(ValueError, match="divisible"):
-        jax.jit(shard_map(
+        jax.jit(jax.shard_map(
             lambda b: dispatch_to_expert_shards(b, "data"),
             mesh=ep_mesh, in_specs=P("data"), out_specs=P("data"),
-            check_rep=False,
+            check_vma=False,
         ))(buf)
 
 
@@ -288,7 +285,7 @@ def test_engine_serves_packed_moe_on_mesh(ep_mesh):
     from repro.models import transformer as tf
     from repro.serving.engine import Engine, ServeConfig
 
-    mesh = jax.make_mesh((4, 1), ("data", "model"))  # ep=4 divides reduced E=4
+    mesh = auto_mesh((4, 1), ("data", "model"))  # ep=4 divides reduced E=4
     cfg = get_config("dbrx_132b").reduced()
     params = tf.init_params(jax.random.PRNGKey(0), cfg)
     eng = Engine(params, cfg, ServeConfig(max_len=32, max_new_tokens=4,

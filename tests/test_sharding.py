@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import auto_mesh
 from repro.parallel import sharding as sh
 from repro.parallel.collectives import quantized_all_gather, wire_decode, wire_encode
 
@@ -58,7 +59,7 @@ def test_scan_stacked_skips_layer_dim():
 
 
 def test_input_sharding_batch_fallbacks():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     s = sh.input_sharding(mesh, (16, 128))
     assert s.spec == P("data", None)
     s1 = sh.input_sharding(mesh, (1,))  # batch=1: not divisible by... size-1 axes divide
@@ -90,9 +91,7 @@ def test_wire_roundtrip_is_razer_accurate():
 
 
 def test_quantized_all_gather_under_shard_map():
-    from jax.experimental.shard_map import shard_map
-
-    mesh = jax.make_mesh((1,), ("fsdp",))
+    mesh = auto_mesh((1,), ("fsdp",))
     rng = np.random.default_rng(1)
     w = jnp.asarray(rng.standard_normal((8, 32)).astype(np.float32))
 
@@ -100,7 +99,7 @@ def test_quantized_all_gather_under_shard_map():
         return quantized_all_gather(shard, "fsdp")
 
     out = jax.jit(
-        shard_map(f, mesh=mesh, in_specs=P("fsdp"), out_specs=P("fsdp"))
+        jax.shard_map(f, mesh=mesh, in_specs=P("fsdp"), out_specs=P("fsdp"))
     )(w)
     back = wire_decode(*wire_encode(w), dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(back), atol=1e-6)

@@ -33,6 +33,7 @@ from repro.core.packing import (
 )
 from repro.core.policy import QuantPolicy
 from repro.core.qlinear import QuantizedLinear, qlinear
+from repro.launch.mesh import auto_mesh
 from repro.models import moe as moe_mod
 from repro.parallel.sharding import (
     kshard_size,
@@ -166,7 +167,7 @@ def test_packed_weight_specs_eligibility(tp_mesh):
         # plain arrays are not packed containers
         assert packed_weight_specs(jnp.ones((64, 32)), ctx) is None
     # tp=1 mesh: nothing to split
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh1 = auto_mesh((1, 1), ("data", "model"))
     assert packed_weight_specs(_dense(), mesh1) is None
 
 
@@ -246,7 +247,7 @@ def test_dense_qlinear_tp_matches_unsharded(tp_mesh):
 
 def test_dense_qlinear_single_device_mesh_bit_exact():
     """A (1, 1) mesh's psum_scatter is the identity: IDENTICAL bits."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     lin = QuantizedLinear(w=_dense())
     pol = QuantPolicy.packed()
     x = jnp.asarray(np.random.default_rng(2).standard_normal((4, 64)), jnp.bfloat16)
