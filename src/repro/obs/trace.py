@@ -12,6 +12,13 @@
   with its **virtual** per-worker clocks, so two runs of the same trace on a
   ``FakeClock`` export byte-identical JSON (deterministic, diffable).
 
+An enabled tracer's ``span()`` also enters a ``jax.profiler.TraceAnnotation``
+of the same name, so any JAX profiler trace taken meanwhile (``Engine.serve(
+profile_dir=...)``, ``launch/serve.py --jax-profile``, the benchmark's traced
+window) holds the program's spans in its ``/host:CPU`` plane on the device
+trace's clock.  With no profiler session open an annotation costs about a
+microsecond.
+
 ``export()`` writes the Chrome trace-event format (`chrome://tracing`,
 https://ui.perfetto.dev): a ``traceEvents`` list of
 ``{name, ph, ts(us), pid, tid, args}`` dicts, sorted per track, with
@@ -21,8 +28,9 @@ B/E nesting, non-negative X durations).
 
 The default recorder is ``NULL_TRACER``, a no-op singleton: ``span()``
 returns one cached null context manager, so the disabled path allocates no
-event records and no per-step objects -- serving with tracing off is the
-untraced hot path, not a cheaper trace.
+event records, no per-step objects and no profiler annotations, and reads
+no clock -- serving with tracing off is the untraced hot path, not a
+cheaper trace.
 """
 from __future__ import annotations
 
@@ -30,13 +38,19 @@ import json
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Tracer", "NullTracer", "NULL_TRACER"]
 
 
 class _Span:
-    """One live B/E pair; created per ``span()`` call on an enabled tracer."""
+    """One live B/E pair; created per ``span()`` call on an enabled tracer.
 
-    __slots__ = ("_tracer", "name", "pid", "tid", "attrs")
+    The profiler annotation is entered after the B reading and left before
+    the E reading, so a clock that raises (the benchmark's window clock
+    ends a run that way) never leaves an annotation open."""
+
+    __slots__ = ("_tracer", "name", "pid", "tid", "attrs", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, pid: int, tid: int,
                  attrs: Dict[str, Any]):
@@ -45,13 +59,17 @@ class _Span:
         self.pid = pid
         self.tid = tid
         self.attrs = attrs
+        self._annotation = None
 
     def __enter__(self) -> "_Span":
-        self._tracer._record("B", self.name, self._tracer._now(),
-                             self.pid, self.tid, self.attrs)
+        ts = self._tracer._now()
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        self._tracer._record("B", self.name, ts, self.pid, self.tid, self.attrs)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        self._annotation.__exit__(exc_type, exc, tb)
         self._tracer._record("E", self.name, self._tracer._now(),
                              self.pid, self.tid, None)
 
@@ -118,7 +136,8 @@ class Tracer:
 
     def span(self, name: str, *, pid: Optional[int] = None,
              tid: Optional[int] = None, **attrs) -> _Span:
-        """Context manager recording a B/E pair around its body."""
+        """Context manager recording a B/E pair around its body, inside a
+        profiler annotation of the same name."""
         return _Span(self, name,
                      self.pid if pid is None else pid,
                      self.tid if tid is None else tid, attrs)

@@ -476,8 +476,12 @@ class Engine:
             and deterministic in tests).  Greedy OUTPUTS never depend on it.
           * ``trace``   -- an ``obs.Tracer``; the loop records the request
             lifecycle (admit / prefill / decode_step / draft / verify /
-            retire) on the serve-relative timeline (the tracer's clock is
-            rebound to it, so trace timestamps line up with arrivals).
+            retire) and the host phases inside each iteration
+            (docs/observability.md) on the serve-relative timeline (the
+            tracer's clock is rebound to it, so trace timestamps line up
+            with arrivals), and, through the tracer's profiler
+            annotations, on the device trace's clock when ``profile_dir``
+            is set.
           * ``metrics`` -- an ``obs.MetricsRegistry``; pool/cache occupancy
             export as function-backed gauges, and TTFT / latency / per-token
             latency / step-duration histograms populate as requests finish.
@@ -535,7 +539,8 @@ class Engine:
             metrics.histogram("serve_decode_step_seconds",
                               "Wall seconds per decode step", labels=("stage",))
             metrics.histogram("serve_prefill_seconds",
-                              "Wall seconds per prefill call", labels=("stage",))
+                              "Wall seconds per prefill, through its first token",
+                              labels=("stage",))
         # the cached speculator accumulates stats across serve() calls;
         # report this run's delta against a snapshot
         spec_base = dataclasses.replace(spec.stats) if spec else None
@@ -588,7 +593,8 @@ class Engine:
         page_table = None
         idle_retries = 0
         while sched.has_work:
-            admitted = sched.admit(now())
+            with tracer.span("sched_admit"):
+                admitted = sched.admit(now())
             if not admitted and not sched.running:
                 # nothing runnable yet: sleep until the next arrival, then
                 # retry admission (the scheduler keeps waiting sorted by
@@ -623,23 +629,30 @@ class Engine:
                     continue
                 if mx:
                     pt = now()
+                # the span ends with the first token on the host: the device
+                # runs the forward and then the page scatter in order, so the
+                # readback waits for both -- the time decode stands still
                 with tracer.span("prefill", rid=req.rid,
                                  tokens=len(req.prompt) - req.cached_tokens,
                                  cached=req.cached_tokens):
-                    if req.cached_tokens:
-                        pool.flush_forks(req.rid)  # COW copy, after donors' writes
-                        last, caches = self._prefill_range(
-                            req.prompt, req.cached_tokens, len(req.prompt), pool, req.rid)
+                    with tracer.span("prefill_forward"):
+                        if req.cached_tokens:
+                            pool.flush_forks(req.rid)  # COW copy, after donors' writes
+                            last, caches = self._prefill_range(
+                                req.prompt, req.cached_tokens, len(req.prompt), pool,
+                                req.rid)
+                        else:
+                            last, caches = self._serve_prefill(req.prompt)
+                    with tracer.span("page_write"):
                         pool.write_prefill(req.rid, caches, len(req.prompt),
                                            start=req.cached_tokens)
-                    else:
-                        last, caches = self._serve_prefill(req.prompt)
-                        pool.write_prefill(req.rid, caches, len(req.prompt))
+                    with tracer.span("prefill_sync"):
+                        first = int(jnp.argmax(last[0]))
                 if mx:
                     prefill_h.observe(now() - pt, stage="engine")
                 prefill_tokens += len(req.prompt) - req.cached_tokens
                 cached_tokens += req.cached_tokens
-                sched.start(req, int(jnp.argmax(last[0])), now())
+                sched.start(req, first, now())
             if admitted:
                 page_table = None
             peak_pages = max(peak_pages, pool.pages_in_use)
@@ -664,15 +677,22 @@ class Engine:
             seq_ids, tokens, cur_lens = batch
             with tracer.span("decode_step", batch=len(sched.running)):
                 if page_table is None:
-                    page_table = pool.page_table(seq_ids)
-                logits, pool.caches = self._paged_decode_jit(
-                    self.params, jnp.asarray(tokens, jnp.int32), pool.caches,
-                    page_table, jnp.asarray(cur_lens, jnp.int32))
-                toks = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+                    with tracer.span("page_table"):
+                        page_table = pool.page_table(seq_ids)
+                # returns once the step is enqueued; decode_sync is the host
+                # blocked on the device
+                with tracer.span("decode_dispatch"):
+                    logits, pool.caches = self._paged_decode_jit(
+                        self.params, jnp.asarray(tokens, jnp.int32), pool.caches,
+                        page_table, jnp.asarray(cur_lens, jnp.int32))
+                with tracer.span("decode_sync"):
+                    toks = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
             decode_steps += 1
             if mx:
                 step_h.observe(now() - st, stage="engine")
-            if sched.post_decode(toks.tolist(), now()):
+            with tracer.span("sched_post_decode"):
+                retired = sched.post_decode(toks.tolist(), now())
+            if retired:
                 page_table = None  # a retirement freed a slot
 
         self._loop_stats = (decode_steps, prefill_tokens, cached_tokens,

@@ -19,6 +19,7 @@ from repro.obs import (
     Tracer,
     percentile,
 )
+from repro.obs import trace as obs_trace
 
 REPO = Path(__file__).resolve().parents[1]
 GOLDEN = Path(__file__).parent / "data" / "trace_golden.json"
@@ -145,6 +146,41 @@ def test_null_tracer_is_allocation_free_noop():
         NULL_TRACER.complete("y", 0.0, -1.0)  # not even validated
         NULL_TRACER.set_track(0, 0, process="p")
     assert NULL_TRACER.events == []
+
+
+@pytest.mark.parametrize("raises_at", ["enter", "exit"])
+def test_tracer_span_leaves_no_annotation_open_when_the_clock_raises(
+        monkeypatch, raises_at):
+    live = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            live.append(self.name)
+
+        def __exit__(self, *exc):
+            live.remove(self.name)
+
+    class WindowOver(Exception):
+        pass
+
+    readings = [] if raises_at == "enter" else [0.5]
+
+    def clock():
+        if not readings:
+            raise WindowOver
+        return readings.pop(0)
+
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", Annotation)
+    t = Tracer(clock=clock)
+    with pytest.raises(WindowOver):
+        with t.span("step"):
+            assert live == ["step"]
+    assert live == []
+    assert t.events == ([] if raises_at == "enter"
+                        else [("B", "step", 0.5, 0, 0, {})])
 
 
 # ---------------------------------------------------------------------------

@@ -1,16 +1,19 @@
 """Observability wired through the serving stack: fake-clock exact latency
 stats, bit-identical outputs with tracing on vs off, deterministic disagg
 traces, span coverage, and the pool/cache/router metric exports."""
+import glob
 import importlib.util
 import json
 from pathlib import Path
 
 import jax
 import pytest
+from jax.profiler import ProfileData
 
 from repro.configs import get_config
 from repro.models import transformer as tf
 from repro.obs import FakeClock, MetricsRegistry, Tracer
+from repro.obs import trace as obs_trace
 from repro.serving.disagg import serve_disagg
 from repro.serving.engine import Engine, ServeConfig
 from repro.serving.pagepool import KVPagePool, PagePoolConfig, install_pool_metrics
@@ -96,6 +99,118 @@ def test_serve_trace_span_coverage_and_validity(tmp_path):
     admits = [e for e in tracer.events if e[1] == "admit"]
     assert [e[5]["rid"] for e in admits] == [0, 1]
     assert admits[1][2] == 1.0
+
+
+# ---------------------------------------------------------------------------
+# the engine loop's host phases: child spans, profiler annotations, and a
+# disabled tracer that costs nothing
+# ---------------------------------------------------------------------------
+PREFILL_CHILDREN = ("prefill_forward", "page_write", "prefill_sync")
+DECODE_CHILDREN = ("page_table", "decode_dispatch", "decode_sync")
+LOOP_SPANS = ("sched_admit", "prefill", "decode_step", "sched_post_decode")
+
+
+def _spans(tracer):
+    """Each B/E pair as (name, start, end, attrs, parent's index), in order."""
+    out, stack = [], []
+    for ph, name, ts, _, _, attrs in tracer.events:
+        if ph == "B":
+            stack.append(len(out))
+            out.append([name, ts, None, attrs, stack[-2] if len(stack) > 1 else None])
+        elif ph == "E":
+            i = stack.pop()
+            assert out[i][0] == name
+            out[i][2] = ts
+    assert not stack
+    return out
+
+
+class _CountingClock(FakeClock):
+    def __init__(self, tick=0.0):
+        super().__init__(tick=tick)
+        self.reads = 0
+
+    def now(self):
+        self.reads += 1
+        return super().now()
+
+
+def test_serve_loop_child_spans_nest_in_their_parents():
+    eng, _ = _engine()
+    tracer = Tracer()
+    # tick > 0: every reading is distinct, so nesting shows strictly
+    rep = eng.serve(_reqs(arrivals=(0.0, 1.0)), trace=tracer,
+                    clock=FakeClock(tick=0.25))
+    spans = _spans(tracer)
+    names = {s[0] for s in spans}
+    assert set(PREFILL_CHILDREN + DECODE_CHILDREN + LOOP_SPANS) <= names
+    for i, (name, start, end, _, parent) in enumerate(spans):
+        if name in PREFILL_CHILDREN + DECODE_CHILDREN:
+            pname, pstart, pend = spans[parent][:3]
+            assert pname == ("prefill" if name in PREFILL_CHILDREN else "decode_step")
+            assert pstart < start < end < pend
+        elif name in LOOP_SPANS:
+            assert parent is None, name  # the loop's phases do not overlap
+    children = {i: [spans[j][0] for j in range(len(spans)) if spans[j][4] == i]
+                for i in range(len(spans))}
+    steps = [i for i, s in enumerate(spans) if s[0] == "decode_step"]
+    assert len(steps) == rep.decode_steps
+    for i in steps:
+        kids = children[i]
+        assert kids.count("decode_sync") == 1 and kids.count("decode_dispatch") == 1
+        assert kids[-1] == "decode_sync" and kids.count("page_table") <= 1
+    # the page table is rebuilt only after an admission or a retirement
+    assert 0 < sum(s[0] == "page_table" for s in spans) < len(steps)
+    prefills = [i for i, s in enumerate(spans) if s[0] == "prefill"]
+    assert len(prefills) == len(rep.requests)
+    for i in prefills:
+        assert children[i] == list(PREFILL_CHILDREN)
+        req = rep.requests[spans[i][3]["rid"]]
+        # the readback is inside the span: the first token comes after it
+        assert req.first_token_time > spans[i][2]
+
+
+def test_tracer_spans_land_in_the_profiler_host_plane(tmp_path):
+    eng, _ = _engine()
+    tracer = Tracer()
+    eng.serve(_reqs(), trace=tracer, profile_dir=str(tmp_path))
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    host = [e.name for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU" for line in plane.lines for e in line.events]
+    recorded = [s[0] for s in _spans(tracer)]
+    assert set(PREFILL_CHILDREN + DECODE_CHILDREN + LOOP_SPANS) <= set(recorded)
+    for name in set(recorded):
+        assert host.count(name) == recorded.count(name), name
+
+
+@pytest.mark.parametrize("metrics", [False, True])
+def test_null_tracer_opens_no_annotation_and_reads_no_extra_clock(monkeypatch, metrics):
+    entered = []
+
+    class Counting:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            pass
+
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", Counting)
+    eng, _ = _engine()
+    clock = _CountingClock()
+    rep = eng.serve(_reqs(), clock=clock,
+                    metrics=MetricsRegistry() if metrics else None)
+    assert entered == []
+    # the loop's readings with tracing off, as they were before the host
+    # phases had spans: serve's start and end, one per admission pass (3),
+    # one per first token (2) and one per decode step's tokens (3); metrics
+    # time each prefill (2 x 2) and each decode step (3 x 2) besides
+    assert rep.decode_steps == 3
+    assert clock.reads == (20 if metrics else 10)
+    eng.serve(_reqs(), trace=Tracer(), clock=FakeClock())
+    assert "decode_sync" in entered  # the patched name is the one spans use
 
 
 def test_serve_speculative_trace_has_draft_verify_spans(tmp_path):
