@@ -60,21 +60,52 @@ def _scatter_pages(pool, src, dst):
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _quantize_scatter(pool, k, v, pids, sids):
-    """Quantize a prefill's K/V (count, S, kvh, hd) and scatter token j into
-    pool page ``pids[j]`` slot ``sids[j]`` -- one compiled call per prefill
-    bucket shape (padded tokens ride along into the null page).  The pool
-    buffers are donated: the caller replaces them with the result, so the
-    update happens in place instead of copying the pool."""
+    """Quantize a prefill's K/V (count, S, kvh, hd) and write token j into pool
+    page ``pids[j]`` slot ``sids[j]`` -- one compiled call per prefill bucket
+    shape (padded tokens ride along into the null page).  The pool buffers
+    are donated: the caller replaces them with the result, so the update
+    happens in place instead of copying the pool.
+
+    The tokens are consecutive positions: token j sits in slot
+    ``(sids[0] + j) % ps`` of the span's page ``(sids[0] + j) // ps``.  The
+    write is a chain of ``dynamic_update_slice``: one whole page per page that
+    tokens fill completely, and slot by slot for the span's first and last
+    page, where slots no token fills (the forked head of a suffix prefill, the
+    slots past the prompt) keep their bytes -- a slot write with no token
+    goes to the null page.  A scatter, or a read of a page to merge with,
+    would make XLA relayout each whole pool buffer, whose pages are the TPU's
+    minor axis: 1.2 GB of temporaries at Qwen3-8B's pool."""
     from repro.serving.kvcache import kv_quantize
 
     kc, km = kv_quantize(k)
     vc, vm = kv_quantize(v)
-    return {
-        "k_codes": pool["k_codes"].at[:, pids, sids].set(kc),
-        "k_meta": pool["k_meta"].at[:, pids, sids].set(km),
-        "v_codes": pool["v_codes"].at[:, pids, sids].set(vc),
-        "v_meta": pool["v_meta"].at[:, pids, sids].set(vm),
-    }
+    ps = pool["k_codes"].shape[2]
+    s = pids.shape[0]
+    n = -(-(s + ps - 1) // ps)  # pages a span of s tokens at any offset touches
+
+    def paged(x):  # (count, S, ...) -> (count, n, ps, ...) on the span's pages
+        pad = [(0, 0)] * x.ndim
+        pad[1] = (ps, n * ps - s)  # pads pids with NULL_PAGE (0): no token there
+        x = jax.lax.dynamic_slice_in_dim(jnp.pad(x, pad), ps - sids[0], n * ps, axis=1)
+        return x.reshape(x.shape[0], n, ps, *x.shape[2:])
+
+    slot_page = paged(pids[None])[0]  # (n, ps)
+    page = jnp.max(slot_page, axis=1)  # the page's id, NULL_PAGE if no token
+    whole = jnp.where((slot_page != NULL_PAGE).all(axis=1), page, NULL_PAGE)
+    last = (sids[0] + jnp.sum(pids != NULL_PAGE) - 1) // ps
+    out = {}
+    for key, x in (("k_codes", kc), ("k_meta", km), ("v_codes", vc), ("v_meta", vm)):
+        buf, new = pool[key], paged(x)
+        zeros = (0,) * (buf.ndim - 3)
+        for i in range(n):
+            buf = jax.lax.dynamic_update_slice(buf, new[:, i:i + 1], (0, whole[i], 0) + zeros)
+        for edge in (0, last):
+            row = jax.lax.dynamic_index_in_dim(new, edge, axis=1)  # (count, 1, ps, ...)
+            for t in range(ps):
+                at = (0, slot_page[edge, t], t) + zeros
+                buf = jax.lax.dynamic_update_slice(buf, row[:, :, t:t + 1], at)
+        out[key] = buf
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -456,13 +487,13 @@ class KVPagePool:
         quantizes per token -- the page is an integer number of quant blocks,
         so this is ``kv_quantize`` applied page-wise unchanged -- and valid
         positions ``[start, length)`` (cache index j holds token ``start + j``;
-        a prefix-cached suffix prefill passes ``start = cached_len``) scatter
-        to ``(page_of(start + j), (start + j) % page_size)`` while the padded
-        tail scatters to the null page.  A nonzero ``start`` never touches the
-        shared prefix pages: they cover tokens ``[0, start)`` only.  Quantize +
-        scatter run as ONE jitted call (cached per bucket shape): the eager
-        per-op path recompiles per prompt shape and dominates serving wall
-        time.
+        a prefix-cached suffix prefill passes ``start = cached_len``) are
+        written to ``(page_of(start + j), (start + j) % page_size)`` while the
+        padded tail goes to the null page; every other slot keeps its bytes.
+        A nonzero ``start`` never touches the shared prefix pages: they cover
+        tokens ``[0, start)`` only.  Quantize + write run as ONE jitted call
+        (cached per bucket shape): the eager per-op path recompiles per prompt
+        shape and dominates serving wall time.
         """
         ps = self.pool_cfg.page_size
         row = np.asarray(self.page_row(seq_id))

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from _hyp import given, settings, st  # optional-hypothesis shim
+from _kv_oracle import kv_oracle
 
 from repro.configs import get_config
 from repro.core.policy import QuantPolicy
@@ -102,6 +103,46 @@ def test_pool_prefill_roundtrip_matches_contiguous_quant():
     np.testing.assert_array_equal(np.asarray(k_pg), np.asarray(want_k))
     vc, vm = kv_quantize(caches[0]["v"][:, 0, :s])
     np.testing.assert_array_equal(np.asarray(v_pg), np.asarray(kv_dequantize(vc, vm, cfg.hd)))
+
+
+@pytest.mark.parametrize("start,length,bucket", [
+    (0, 13, 16),   # a whole prompt; the padded tail runs past its last page
+    (16, 29, 16),  # a suffix after a page-aligned cached prefix
+    (11, 30, 32),  # a suffix from mid-page: the page's head is the prefix's
+    (3, 6, 8),     # a suffix inside one page, padded tail in the same page
+    (5, 13, 16),   # a suffix over two partial pages
+], ids=["prompt", "suffix-aligned", "suffix-mid-page", "suffix-one-page", "suffix-two-pages"])
+def test_write_prefill_pages_match_reference_scatter(start, length, bucket):
+    """The pages ``write_prefill`` writes hold, byte for byte, the general
+    RaZeR path's wire bytes of tokens ``[start, length)`` at their (page,
+    slot); every slot no token lands in keeps its bytes, in every page but
+    the null page (a sink)."""
+    cfg = _cfg()
+    ps = 8
+    pool = KVPagePool(cfg, PagePoolConfig(num_pages=12, page_size=ps, max_len=64))
+    rng = np.random.default_rng(start * 100 + length)
+    pool.caches = [{key: jnp.asarray(rng.integers(0, 256, buf.shape), jnp.uint8)
+                    for key, buf in group.items()} for group in pool.caches]
+    before = [{key: np.asarray(buf) for key, buf in group.items()} for group in pool.caches]
+    pool.allocate(0, length)
+    caches = [{
+        name: jnp.asarray(rng.standard_normal((count, 1, bucket, cfg.num_kv_heads, cfg.hd)),
+                          jnp.bfloat16)
+        for name in ("k", "v")
+    } for _, count in tf.layer_groups(cfg)]
+    pool.write_prefill(0, caches, length, start=start)
+    row = pool.page_row(0)
+    for gi, c in enumerate(caches):
+        want = {key: buf.copy() for key, buf in before[gi].items()}
+        for name in ("k", "v"):
+            codes, meta = (np.asarray(a) for a in kv_oracle(c[name][:, 0]))
+            for j in range(length - start):
+                page, slot = row[(start + j) // ps], (start + j) % ps
+                want[f"{name}_codes"][:, page, slot] = codes[:, j]
+                want[f"{name}_meta"][:, page, slot] = meta[:, j]
+        for key, buf in want.items():
+            got = np.asarray(pool.caches[gi][key])
+            np.testing.assert_array_equal(got[:, NULL_PAGE + 1:], buf[:, NULL_PAGE + 1:], err_msg=key)
 
 
 # ---------------------------------------------------------------------------

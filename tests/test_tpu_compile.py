@@ -16,11 +16,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.packing import PackedRazerWeight, PackedStackedTensor
 from repro.kernels import ops
+from repro.serving.pagepool import _quantize_scatter
 
 # llama3.2-3b: d=3072, 24/8 heads, hd=128, d_ff=8192; dbrx-132b expert bank
 D, D_FF, H, KVH, HD = 3072, 8192, 24, 8, 128
 DBRX_E, DBRX_D, DBRX_F = 16, 6144, 10752
 SLOTS, PAGE, PAGES_PER_SEQ = 8, 16, 32
+QWEN3_8B_LAYERS, QWEN3_8B_POOL_PAGES = 36, 2048 + 1  # + the null page
 
 
 @pytest.fixture(scope="module")
@@ -57,9 +59,9 @@ def _compile_text(fn, *args):
     return [line for line in text.splitlines() if "tpu_custom_call" in line]
 
 
-def _pool(sharding, pages):
+def _pool(sharding, pages, lead=()):
     return {
-        name: _spec(sharding, (pages, PAGE, KVH, width), jnp.uint8)
+        name: _spec(sharding, (*lead, pages, PAGE, KVH, width), jnp.uint8)
         for name, width in (("k_codes", HD // 2), ("k_meta", HD // 16),
                             ("v_codes", HD // 2), ("v_meta", HD // 16))
     }
@@ -127,3 +129,16 @@ def test_contiguous_kv_attention_compiles(one_chip):
         _spec(one_chip, (SLOTS, 1, H, HD), jnp.bfloat16), cache,
         _spec(one_chip, (SLOTS,), jnp.int32))
     assert len(calls) == 1 and "%razer_kv_attention" in calls[0]
+
+
+def test_page_scatter_compiles_in_place_at_qwen3_8b_width(one_chip):
+    """A 512-token prefill's page write into a Qwen3-8B pool (36 layers of
+    2049 pages) needs under 0.5 GB of temporaries: the donated pool is
+    updated in place, not relaid out, and the encoder keeps no full-size
+    intermediate."""
+    tokens = 512
+    kv = _spec(one_chip, (QWEN3_8B_LAYERS, tokens, KVH, HD), jnp.bfloat16)
+    idx = _spec(one_chip, (tokens,), jnp.int32)
+    pool = _pool(one_chip, QWEN3_8B_POOL_PAGES, lead=(QWEN3_8B_LAYERS,))
+    compiled = _quantize_scatter.lower(pool, kv, kv, idx, idx).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
